@@ -17,10 +17,11 @@ import numpy as np                                       # noqa: E402
 from repro.distributed.pipeline import (                 # noqa: E402
     pipeline_forward, split_stages,
 )
+from repro.launch.mesh import make_mesh                  # noqa: E402
 
 
 def main():
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
     n_layers, d, t_micro, mb = 16, 64, 8, 4
     keys = jax.random.split(jax.random.PRNGKey(0), n_layers)
     w = jnp.stack([jax.random.normal(k, (d, d)) * 0.2 for k in keys])

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 
 __all__ = ["sp_decode_attention", "sp_attention_shardmap"]
 
@@ -63,7 +62,7 @@ def sp_attention_shardmap(mesh, axis: str = "model"):
     (cache seq dim on ``axis``), gets full attention out."""
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P(None, axis, None, None),
                   P(None, axis, None, None), P(None, axis), P()),
         out_specs=P(),

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import pvary, shard_map
 
 __all__ = ["pipeline_forward", "split_stages"]
 
@@ -56,7 +55,7 @@ def pipeline_forward(stage_params, x_micro, block_fn, mesh, *,
         return x
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
     )
@@ -66,8 +65,8 @@ def pipeline_forward(stage_params, x_micro, block_fn, mesh, *,
         sid = jax.lax.axis_index(axis)
         # carries become device-varying through ppermute/axis_index; mark
         # the initial values varying so the scan carry type is stable
-        state = pvary(jnp.zeros_like(xs[0]), (axis,))
-        outs = pvary(jnp.zeros_like(xs), (axis,))
+        state = jax.lax.pcast(jnp.zeros_like(xs[0]), (axis,), to="varying")
+        outs = jax.lax.pcast(jnp.zeros_like(xs), (axis,), to="varying")
 
         def tick(carry, t):
             state, outs = carry

@@ -279,6 +279,7 @@ def main(argv=None):
 
     import repro.configs as configs
     from repro.configs.base import reduce as reduce_cfg
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import lm
 
     ap = argparse.ArgumentParser()
@@ -316,6 +317,7 @@ def main(argv=None):
     ap.add_argument("--snapshot", type=str, default=None,
                     help="write the full metrics snapshot JSON here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.reduced:

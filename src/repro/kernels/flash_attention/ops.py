@@ -6,14 +6,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
-from repro.kernels.flash_attention.ref import attention_ref
 
 __all__ = ["flash_attention"]
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(
@@ -31,23 +27,22 @@ def flash_attention(
 ) -> jax.Array:
     """(B, Hq, Sq, D) x (B, Hkv, Skv, D) -> (B, Hq, Sq, D), seq padded."""
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     b, hq, sq, d = q.shape
     skv = k.shape[2]
     bq_ = min(bq, max(8, sq))
     bk_ = min(bk, max(8, skv))
     pq = (-sq) % bq_
     pkv = (-skv) % bk_
+    # padded kv columns lie above the causal diagonal of every real query;
+    # without causality nothing would mask them, so refuse that shape
+    if not causal and pkv:
+        raise ValueError(
+            f"non-causal flash attention needs the kv length {skv} to be a "
+            f"multiple of the kv block {bk_}")
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, pq), (0, 0)))
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, pkv), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, pkv), (0, 0)))
-    # padded kv columns must not contribute: they are masked by causality
-    # for decode-style queries only when causal; for safety mask via large
-    # negative K (set padded K rows to 0 and rely on causal mask when
-    # causal; for non-causal, bias via masking in kernel is not available,
-    # so fall back to ref on ragged non-causal shapes).
-    if not causal and pkv:
-        return attention_ref(q, k, v, causal=False)
     out = flash_attention_fwd(
         qp, kp, vp, bq=bq_, bk=bk_, causal=causal, interpret=interpret,
         scale=d ** -0.5,

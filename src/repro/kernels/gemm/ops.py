@@ -1,4 +1,4 @@
-"""Public GeMM ops: padding, dtype policy, CPU-interpret fallback.
+"""Public GeMM ops: padding, dtype policy, interpret mode on the CPU.
 
 These are the ``compute_fns`` registered for the GeMM accelerator: plain
 matmul, dense (FC) and conv2d lowered to implicit GEMM via im2col — the
@@ -11,15 +11,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_interpret
 from repro.kernels.gemm import ref
 from repro.kernels.gemm.kernel import gemm
 
-__all__ = ["matmul", "dense", "conv2d_as_gemm", "use_interpret"]
-
-
-def use_interpret() -> bool:
-    """Pallas-TPU lowers only on TPU; everywhere else run interpret mode."""
-    return jax.default_backend() != "tpu"
+__all__ = ["matmul", "dense", "conv2d_as_gemm"]
 
 
 def _pad_to(x: jax.Array, mults: tuple[int, int]) -> jax.Array:

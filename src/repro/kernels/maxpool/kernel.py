@@ -20,9 +20,13 @@ __all__ = ["maxpool"]
 
 def _maxpool_body(x_ref, o_ref, *, k: int):
     x = x_ref[...]                       # (1, H, W, bc)
+    if jnp.issubdtype(x.dtype, jnp.integer):
+        # the TPU compiler has no int8 reductions: widen, reduce, narrow
+        # (exact, max commutes with the widening)
+        x = x.astype(jnp.int32)
     _, h, w, bc = x.shape
     x = x.reshape(1, h // k, k, w // k, k, bc)
-    o_ref[...] = jnp.max(x, axis=(2, 4))
+    o_ref[...] = jnp.max(x, axis=(2, 4)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bc", "interpret"))

@@ -2,14 +2,9 @@
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
+from repro.kernels import use_interpret
 from repro.kernels.maxpool.kernel import maxpool
-from repro.kernels.maxpool.ref import maxpool2d_ref
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def maxpool2d(attrs: dict, x: jax.Array) -> jax.Array:
@@ -17,7 +12,7 @@ def maxpool2d(attrs: dict, x: jax.Array) -> jax.Array:
     c = x.shape[-1]
     bc = attrs.get("bc", min(128, c))
     if c % bc:
-        # channel count not blockable -> host path (placement puts such
-        # shapes on the RISC-V core anyway; keep the op total).
-        return maxpool2d_ref(x, k)
-    return maxpool(x, k=k, bc=bc, interpret=_use_interpret())
+        raise ValueError(
+            f"maxpool accelerator: {c} channels of input {x.shape} are not "
+            f"a multiple of the channel block {bc}")
+    return maxpool(x, k=k, bc=bc, interpret=use_interpret())

@@ -6,13 +6,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_interpret
 from repro.kernels.rmsnorm.kernel import rmsnorm_fwd
 
 __all__ = ["rmsnorm"]
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "bm", "interpret"))
@@ -25,7 +22,7 @@ def rmsnorm(
     interpret: bool | None = None,
 ) -> jax.Array:
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     shape = x.shape
     d = shape[-1]
     rows = 1

@@ -28,7 +28,8 @@ __all__ = ["ssd_fwd"]
 NEG = -1e30
 
 
-def _ssd_body(xdt_ref, b_ref, c_ref, lcum_ref, o_ref, s_ref, *, q: int):
+def _ssd_body(xdt_ref, b_ref, c_ref, lrow_ref, lcol_ref, o_ref, s_ref, *,
+              q: int):
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
@@ -38,12 +39,16 @@ def _ssd_body(xdt_ref, b_ref, c_ref, lcum_ref, o_ref, s_ref, *, q: int):
     xdt = xdt_ref[0, 0, 0].astype(jnp.float32)        # (Q, P)
     b = b_ref[0, 0].astype(jnp.float32)               # (Q, N)
     c = c_ref[0, 0].astype(jnp.float32)               # (Q, N)
-    lc = lcum_ref[0, 0, 0].astype(jnp.float32)        # (Q,)
+    # the log-decays arrive as a row and as a column, so that no lane <->
+    # sublane transpose is needed in the kernel
+    lrow = lrow_ref[0, 0, 0].astype(jnp.float32)      # (1, Q)
+    lcol = lcol_ref[0, 0, 0].astype(jnp.float32)      # (Q, 1)
+    llast = lcol[q - 1, 0]                            # scalar
 
     # intra-chunk: scores[i, j] = (C_i . B_j) * exp(lc_i - lc_j), j <= i
     cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (Q,Q)
-    ldiff = lc[:, None] - lc[None, :]
+    ldiff = lcol - lrow
     mask = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     dec = jnp.exp(jnp.where(mask, ldiff, NEG))
@@ -53,16 +58,16 @@ def _ssd_body(xdt_ref, b_ref, c_ref, lcum_ref, o_ref, s_ref, *, q: int):
 
     # inter-chunk: y_i += exp(lc_i) * C_i . S_prev
     s_prev = s_ref[...]                               # (N, P)
-    y_inter = jnp.exp(lc)[:, None] * jax.lax.dot_general(
+    y_inter = jnp.exp(lcol) * jax.lax.dot_general(
         c, s_prev, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     o_ref[0, 0, 0] = (y_intra + y_inter).astype(o_ref.dtype)
 
     # state update: S = exp(lc_Q) * S_prev + B^T (exp(lc_Q - lc_j) * xdt)
-    tail = jnp.exp(lc[-1] - lc)                       # (Q,)
-    s_new = s_prev * jnp.exp(lc[-1]) + jax.lax.dot_general(
-        b, tail[:, None] * xdt, (((0,), (0,)), ((), ())),
+    tail = jnp.exp(llast - lcol)                      # (Q, 1)
+    s_new = s_prev * jnp.exp(llast) + jax.lax.dot_general(
+        b, tail * xdt, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     s_ref[...] = s_new
 
@@ -80,11 +85,14 @@ def ssd_fwd(xdt, bmat, cmat, lcum, *, interpret: bool = False):
                          lambda b, hh, c_: (b, hh, c_, 0, 0)),
             pl.BlockSpec((1, 1, q, n), lambda b, hh, c_: (b, c_, 0, 0)),
             pl.BlockSpec((1, 1, q, n), lambda b, hh, c_: (b, c_, 0, 0)),
-            pl.BlockSpec((1, 1, 1, q), lambda b, hh, c_: (b, hh, c_, 0)),
+            pl.BlockSpec((1, 1, 1, 1, q),
+                         lambda b, hh, c_: (b, hh, c_, 0, 0)),
+            pl.BlockSpec((1, 1, 1, q, 1),
+                         lambda b, hh, c_: (b, hh, c_, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, q, p),
                                lambda b, hh, c_: (b, hh, c_, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(xdt.shape, xdt.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(xdt, bmat, cmat, lcum)
+    )(xdt, bmat, cmat, lcum[..., None, :], lcum[..., :, None])

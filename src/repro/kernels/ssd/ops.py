@@ -5,13 +5,10 @@ import functools
 
 import jax
 
+from repro.kernels import use_interpret
 from repro.kernels.ssd.kernel import ssd_fwd
 
 __all__ = ["ssd_chunked"]
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -19,5 +16,5 @@ def ssd_chunked(xdt, bmat, cmat, lcum, *, interpret: bool | None = None):
     """Chunked SSD scan. Shapes as in ``kernel.ssd_fwd``; the caller
     (``repro.models.mamba2``) prepares dt-weighted inputs and log-decays."""
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     return ssd_fwd(xdt, bmat, cmat, lcum, interpret=interpret)

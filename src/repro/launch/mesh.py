@@ -11,8 +11,17 @@ jax device state (the dry-run pins the device count before first jax use).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_local_mesh"]
+__all__ = ["make_mesh", "make_production_mesh", "make_local_mesh"]
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with Auto axes: the compiler propagates shardings
+    from the jit in/out shardings, as the sharding rules expect
+    (``jax.make_mesh`` defaults to Explicit axes, which demand per-op
+    ``out_sharding`` annotations)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -26,10 +35,10 @@ def make_production_mesh(*, multi_pod: bool = False,
                  else (16, 16 // split_model, split_model))
         axes = (("pod", "data", "model", "model_b") if multi_pod
                 else ("data", "model", "model_b"))
-        return jax.make_mesh(shape, axes)
+        return make_mesh(shape, axes)
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(*, data: int | None = None, model: int = 1):
@@ -37,4 +46,4 @@ def make_local_mesh(*, data: int | None = None, model: int = 1):
     n = len(jax.devices())
     if data is None:
         data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

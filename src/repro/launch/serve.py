@@ -70,6 +70,7 @@ import numpy as np
 import repro.configs as configs
 from repro.configs.base import reduce as reduce_cfg
 from repro.gateway.metrics import RingBuffer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.runtime.executor import DeviceQueue
 from repro.runtime.faults import FaultError, FaultPlan
@@ -1015,6 +1016,7 @@ def main(argv=None):
     ap.add_argument("--defer-cap", type=int, default=None,
                     help="pool-dry deferrals before a request is rejected")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.reduced:

@@ -20,6 +20,7 @@ import repro.configs as configs
 from repro.configs.base import reduce as reduce_cfg
 from repro.data.pipeline import SyntheticSource, TokenFileSource
 from repro.data.synthetic import make_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models import lm
 from repro.optim.adamw import adamw_init, adamw_update
@@ -101,6 +102,7 @@ def main(argv=None):
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--peak-lr", type=float, default=3e-4)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.reduced:

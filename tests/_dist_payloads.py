@@ -3,14 +3,14 @@ XLA_FLAGS=--xla_force_host_platform_device_count=8 (see test_distributed).
 Each function prints 'PASS <name>' on success."""
 import sys
 
+from repro.launch.mesh import make_mesh
+
 
 def payload_sharding_rules():
-    import jax
-    import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding.rules import resolve_leaf, zero1_sharding
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     # mlp dim divisible by 4 -> sharded
     assert resolve_leaf(("embed", "mlp"), (64, 128), mesh) == P(None,
                                                                 "model")
@@ -41,7 +41,7 @@ def payload_e2e_sharded_train():
     from repro.sharding.rules import batch_specs
 
     cfg = reduce(configs.get("smollm_135m"))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     params, opt, p_sh, o_sh = make_sharded_state(cfg, mesh)
     src = SyntheticSource(cfg, batch=4, seq=32)
     batch0, _ = src.get(DataState())
@@ -73,7 +73,7 @@ def payload_pipeline_forward():
     import numpy as np
 
     from repro.distributed.pipeline import pipeline_forward, split_stages
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
     n_layers, d = 8, 16
     keys = jax.random.split(jax.random.PRNGKey(0), n_layers)
     w = jnp.stack([
@@ -104,7 +104,7 @@ def payload_flash_decode_sp():
 
     from repro.distributed.flash_decode import sp_attention_shardmap
     from repro.kernels.flash_attention.ref import attention_ref
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     b, h, kv, s, d = 2, 8, 4, 64, 16
     kq, kk, kv_ = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(kq, (b, h, d))
@@ -136,11 +136,9 @@ def payload_compressed_psum():
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.compression import compressed_psum_mean
-    mesh = jax.make_mesh((8,), ("pod",))
+    mesh = make_mesh((8,), ("pod",))
 
-    from repro.distributed.compat import shard_map
-
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P("pod"), P("pod")), out_specs=P("pod"))
     def run(x, err):
         m, e = compressed_psum_mean(x[0], "pod", err[0])
@@ -173,7 +171,7 @@ def payload_elastic_restore():
     import tempfile
 
     cfg = reduce(configs.get("smollm_135m"))
-    mesh1 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh1 = make_mesh((2, 4), ("data", "model"))
     params_s, specs = lm.abstract_params(cfg)
     sh1 = param_shardings(specs, params_s, mesh1)
     with mesh1:
@@ -181,7 +179,7 @@ def payload_elastic_restore():
                          out_shardings=sh1)(jax.random.PRNGKey(0))
     with tempfile.TemporaryDirectory() as d:
         save_checkpoint(d, 1, params)
-        mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh2 = make_mesh((4, 2), ("data", "model"))
         sh2 = param_shardings(specs, params_s, mesh2)
         restored, _ = load_checkpoint(d, 1, params_s, shardings=sh2)
         a = np.asarray(jax.device_get(
@@ -200,7 +198,7 @@ def payload_pipeline_grad():
     import numpy as np
 
     from repro.distributed.pipeline import pipeline_forward, split_stages
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
     n_layers, d = 8, 8
     keys = jax.random.split(jax.random.PRNGKey(0), n_layers)
     w = jnp.stack([jax.random.normal(k, (d, d)) * 0.3 for k in keys])

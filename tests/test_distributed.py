@@ -24,6 +24,9 @@ PAYLOADS = [
 @pytest.mark.parametrize("name", PAYLOADS)
 def test_distributed_payload(name):
     env = dict(os.environ)
+    # the child never reaches for an accelerator: a chip belongs to one
+    # process, and this parent may hold it
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run(

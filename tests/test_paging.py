@@ -1,7 +1,9 @@
 """Unit tests for the paged KV substrate: PagePool refcount invariants,
-PrefixTree match/insert/evict semantics, and bit-equivalence of the paged
-cache layout against the dense one at the ``lm`` level (including int8
-KV quantization and shared-prefix tail prefill).
+PrefixTree match/insert/evict semantics, and agreement of the paged cache
+layout with the dense one at the ``lm`` level (including int8 KV
+quantization and shared-prefix tail prefill).  The two layouts are
+different programs, so their logits agree within the float32 tolerance
+of ``repro.models.reference``, not bit for bit.
 """
 import jax
 import jax.numpy as jnp
@@ -11,6 +13,7 @@ import pytest
 import repro.configs as configs
 from repro.configs.base import reduce
 from repro.models import lm
+from repro.models.reference import LOGIT_TOL, logit_error
 from repro.serving import PagePool, PrefixTree
 
 
@@ -114,7 +117,7 @@ def test_tree_eviction_prefers_least_recently_used():
     assert pool.refs[a[0]] == 1
 
 
-# ==================================================== paged == dense bits ==
+# ================================================== paged ~= dense logits ==
 @pytest.fixture(scope="module")
 def smollm():
     cfg = reduce(configs.get("smollm_135m"))
@@ -126,21 +129,29 @@ def _seq_table(start, n):
     return jnp.asarray(list(range(start, start + n)), jnp.int32)
 
 
+def _assert_logits_close(got, want):
+    # float32 config: only the summation order differs between layouts
+    err = logit_error(got, want)
+    assert err <= LOGIT_TOL["float32"], err
+
+
 def _decode_compare(cfg, params, dense, paged, steps, t0):
-    td = tp = t0
+    tok = t0
     for _ in range(steps):
-        ld, dense = lm.decode_step(params, td, dense, cfg)
-        lp, paged = lm.decode_step(params, tp, paged, cfg)
-        np.testing.assert_array_equal(np.asarray(ld), np.asarray(lp))
-        td = jnp.argmax(ld[:, 0], -1)[:, None].astype(jnp.int32)
-        tp = jnp.argmax(lp[:, 0], -1)[:, None].astype(jnp.int32)
+        ld, dense = lm.decode_step(params, tok, dense, cfg)
+        lp, paged = lm.decode_step(params, tok, paged, cfg)
+        _assert_logits_close(lp, ld)
+        # both layouts are fed the same token, so a near-tie argmax
+        # cannot send them down different sequences
+        tok = jnp.argmax(ld[:, 0], -1)[:, None].astype(jnp.int32)
 
 
 @pytest.mark.parametrize("quant", [False, True])
 def test_paged_prefill_and_decode_match_dense_bitwise(smollm, quant):
     """Same tokens through the dense and the paged layout (max_len not a
-    page multiple, so the paged view is wider) must produce bit-identical
-    logits at prefill and every decode step."""
+    page multiple, so the paged view is wider) must produce the same
+    logits, within the float32 tolerance, at prefill and every decode
+    step."""
     import dataclasses
     cfg, params = smollm
     cfg = dataclasses.replace(cfg, kv_quant=quant)
@@ -154,7 +165,7 @@ def test_paged_prefill_and_decode_match_dense_bitwise(smollm, quant):
     paged = lm.install_pages(paged, 1, _seq_table(4, 4), 0, cfg)
     ld, dense = lm.prefill_into(params, toks, dense, cfg)
     lp, paged = lm.prefill_into(params, toks, paged, cfg)
-    np.testing.assert_array_equal(np.asarray(ld), np.asarray(lp))
+    _assert_logits_close(lp, ld)
     _decode_compare(cfg, params, dense, paged, 5,
                     jnp.argmax(ld, -1)[:, None].astype(jnp.int32))
 
@@ -162,8 +173,8 @@ def test_paged_prefill_and_decode_match_dense_bitwise(smollm, quant):
 def test_shared_prefix_tail_prefill_matches_solo_dense(smollm):
     """Slot B seeded with slot A's full prefix pages and prefilled only on
     its tail must match a solo dense prefill of the whole prompt — and
-    B's writes must not disturb the shared pages (A keeps decoding
-    bit-identically afterwards)."""
+    B's writes must not disturb the shared pages (A keeps decoding in
+    step with the dense reference afterwards)."""
     cfg, params = smollm
     P = 4
     rng = np.random.default_rng(7)
@@ -188,7 +199,7 @@ def test_shared_prefix_tail_prefill_matches_solo_dense(smollm):
     ta[0, :12] = pa
     la, paged = lm.prefill_into(params, jnp.asarray(ta), paged, cfg,
                                 seq_lens=jnp.asarray([12, 0], jnp.int32))
-    np.testing.assert_array_equal(np.asarray(la[0]), np.asarray(lr[0]))
+    _assert_logits_close(la[0], lr[0])
     # B shares A's first two pages (8 tokens), gets private tail pages
     paged = lm.install_pages(
         paged, 1, jnp.asarray([0, 1, 5, 6, 7], jnp.int32), 8, cfg)
@@ -196,7 +207,7 @@ def test_shared_prefix_tail_prefill_matches_solo_dense(smollm):
     tb[1, :5] = pb[8:]
     lb, paged = lm.prefill_into(params, jnp.asarray(tb), paged, cfg,
                                 seq_lens=jnp.asarray([0, 5], jnp.int32))
-    np.testing.assert_array_equal(np.asarray(lb[1]), np.asarray(lr[1]))
+    _assert_logits_close(lb[1], lr[1])
     # both rows keep decoding in lockstep with the dense reference
     t0 = jnp.stack([jnp.argmax(la[0]), jnp.argmax(lb[1])]).astype(
         jnp.int32)[:, None]

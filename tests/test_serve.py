@@ -17,6 +17,7 @@ import repro.configs as configs
 from repro.configs.base import reduce
 from repro.launch.serve import Request, Server, drain, solo_reference
 from repro.models import lm
+from repro.models.reference import LOGIT_TOL, logit_error
 
 
 @pytest.fixture(scope="module")
@@ -237,8 +238,9 @@ def test_dense_fallback_still_serves(smollm):
 
 
 def test_prefill_into_matches_forward_last_logits(smollm):
-    """The cache-writing batched prefill must agree bit-for-bit with the
-    full-sequence forward at the last position (same einsum path)."""
+    """The cache-writing batched prefill must agree with the full-sequence
+    forward at the last position.  They are different programs (cached
+    vs whole-sequence attention), so within the float32 tolerance."""
     cfg, params = smollm
     rng = np.random.default_rng(5)
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 6)), jnp.int32)
@@ -247,4 +249,5 @@ def test_prefill_into_matches_forward_last_logits(smollm):
     caches = lm.init_caches(cfg, 2, 16)
     last, _ = jax.jit(lambda p, t, c: lm.prefill_into(p, t, c, cfg))(
         params, toks, caches)
-    np.testing.assert_array_equal(np.asarray(last), np.asarray(full[:, -1]))
+    err = logit_error(last, full[:, -1])
+    assert err <= LOGIT_TOL["float32"], err
