@@ -37,6 +37,7 @@ import time
 
 import numpy as np
 
+from repro import obs
 from repro.gateway.admission import AdmissionScheduler
 from repro.gateway.api import (
     CompletionRequest, CompletionResponse, Rejection, StreamChunk, Usage,
@@ -197,8 +198,17 @@ class Gateway:
         """One gateway step: dispatch due admissions, tick the server,
         poll streams, sample gauges.  Returns whether the server's tick
         dispatched any decode work."""
-        self.steps += 1
-        # 1. admissions: the scheduler picks who and how many
+        with obs.span("gateway.step"):
+            self.steps += 1
+            with obs.span("gateway.admit"):
+                self._admit()
+            ticked = self.server.tick()      # one lockstep decode tick
+            with obs.span("gateway.poll"):
+                self._poll()
+            return ticked
+
+    def _admit(self) -> None:
+        """Admissions: the scheduler picks who and how many."""
         ready, expired = self.sched.dispatch(self._free_slots(),
                                              health=self.server.health)
         for rej in expired:
@@ -232,9 +242,10 @@ class Gateway:
                     creq.rid, reason, "refused at server admission"))
                 continue
             self._note("admit", creq.rid)
-        # 2. one lockstep decode tick
-        ticked = self.server.tick()
-        # 3. poll every in-flight stream for its delta
+
+    def _poll(self) -> None:
+        """Poll every in-flight stream for its delta, finalise retired
+        requests, and sample the gauges."""
         now = self.clock()
         for rid, live in list(self._live.items()):
             sreq = live.sreq
@@ -261,7 +272,6 @@ class Gateway:
                     live.chunks.append(StreamChunk(rid, new))
             if sreq.done:
                 self._finalize_response(live)
-        # 4. observability gauges
         busy = sum(s is not None for s in self.server.slots)
         pool_util = 0.0
         if self.server.paged:
@@ -271,7 +281,6 @@ class Gateway:
         self.metrics.sample(queue_depth=self.sched.depth,
                             slot_utilization=busy / self.server.batch,
                             pool_utilization=pool_util)
-        return ticked
 
     # ------------------------------------------------------------- stream
     def chunks(self, rid: str) -> list[StreamChunk]:

@@ -201,9 +201,11 @@ class DisaggServer(Server):
         self.decoder = DecodeWorker(self)
         self.ledger = HandoffLedger()
         self.pending: list[_PendingPrefill] = []
-        self._migrate = jax.jit(
-            lambda s, d, si, di: lm.migrate_pages(s, d, si, di, cfg),
-            donate_argnums=(1,))
+
+        def migrate_pages(s, d, si, di):
+            return lm.migrate_pages(s, d, si, di, cfg)
+
+        self._migrate = jax.jit(migrate_pages, donate_argnums=(1,))
         self.transfers = 0
         self.pages_transferred = 0
         self.overlap_ticks = 0
@@ -309,7 +311,7 @@ class DisaggServer(Server):
         return "admitted"
 
     # ------------------------------------------------------ tick machine
-    def tick(self) -> bool:
+    def _tick(self) -> bool:
         """Decode dispatch -> prefill completion -> decode collect.
 
         The completion pass sits *between* dispatch and collect on

@@ -68,6 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.configs as configs
+from repro import obs
 from repro.configs.base import reduce as reduce_cfg
 from repro.gateway.metrics import RingBuffer
 from repro.launch.compile_cache import enable_compile_cache
@@ -160,16 +161,22 @@ def serving_fns(cfg, *, donate: bool = False):
     reference keeps its caches undonated so repeated checks can share
     executables."""
     kw: dict = {"donate_argnums": (2,)} if donate else {}
-    prefill = jax.jit(
-        lambda p, t, c, sl: lm.prefill_into(p, t, c, cfg, seq_lens=sl),
-        **kw)
-    decode = jax.jit(
-        lambda p, t, c, sl: lm.decode_step(p, t, c, cfg, seq_lens=sl),
-        **kw)
-    return prefill, decode
+
+    # named, so a device trace shows jit_serve_prefill / jit_serve_decode
+    def serve_prefill(p, t, c, sl):
+        return lm.prefill_into(p, t, c, cfg, seq_lens=sl)
+
+    def serve_decode(p, t, c, sl):
+        return lm.decode_step(p, t, c, cfg, seq_lens=sl)
+
+    return jax.jit(serve_prefill, **kw), jax.jit(serve_decode, **kw)
 
 
 _REF_FNS: dict = {}
+
+# span of one step dispatch, by ``Server._submit`` site
+_DISPATCH_SPANS = {"prefill": "model.prefill_dispatch",
+                   "decode": "model.decode_dispatch"}
 
 
 def _ref_fns(cfg):
@@ -347,11 +354,15 @@ class Server:
         # pages referenced by each slot's table (paged mode bookkeeping)
         self.slot_pages: list[list[int] | None] = [None] * batch
         self._prefill, self._decode = serving_fns(cfg, donate=True)
-        self._reset = jax.jit(
-            lambda c, s: lm.reset_slot(c, s, cfg), donate_argnums=(0,))
-        self._install = jax.jit(
-            lambda c, s, t, n: lm.install_pages(c, s, t, n, cfg),
-            donate_argnums=(0,))
+
+        def reset_slot(c, s):
+            return lm.reset_slot(c, s, cfg)
+
+        def install_pages(c, s, t, n):
+            return lm.install_pages(c, s, t, n, cfg)
+
+        self._reset = jax.jit(reset_slot, donate_argnums=(0,))
+        self._install = jax.jit(install_pages, donate_argnums=(0,))
         self.queue = DeviceQueue("decode", injector=self.inject)
         self.ticks = 0               # ticks that dispatched a decode
         self.clock = 0               # every tick() call (drives timers)
@@ -404,7 +415,8 @@ class Server:
         delay = self.policy.backoff_s
         for attempt in range(self.policy.max_retries + 1):
             try:
-                return q.submit(fn, *args, site=site)
+                with obs.span(_DISPATCH_SPANS[site]):
+                    return q.submit(fn, *args, site=site)
             except FaultError:
                 self.faults_detected += 1
                 self._tick_faults += 1
@@ -568,11 +580,12 @@ class Server:
             raise ValueError(
                 f"request {req.rid} needs {n_total} pages > pool capacity "
                 f"{self.pool_pages} — it could never be admitted")
-        shared, shared_len = tree.match(req.prompt)
-        n_priv = n_total - len(shared)
-        if pool.free_pages < n_priv:
-            tree.evict(n_priv - pool.free_pages)
-        priv = pool.alloc(n_priv)
+        with obs.span("server.prefix_match"):
+            shared, shared_len = tree.match(req.prompt)
+            n_priv = n_total - len(shared)
+            if pool.free_pages < n_priv:
+                tree.evict(n_priv - pool.free_pages)
+            priv = pool.alloc(n_priv)
         if priv is None:
             # every evictable page is pinned by an active request: defer
             # admission (a later retirement will release pages)
@@ -584,11 +597,12 @@ class Server:
         table = shared + priv
         self.slots[slot] = req
         self.slot_pages[slot] = table
-        row_table = np.full((self.n_slot_pages,), -1, np.int32)
-        row_table[:len(table)] = table
-        self.caches[shard] = self.queue.submit(
-            self._install, self.caches[shard], jnp.int32(row),
-            jnp.asarray(row_table), jnp.int32(shared_len))
+        with obs.span("server.page_install"):
+            row_table = np.full((self.n_slot_pages,), -1, np.int32)
+            row_table[:len(table)] = table
+            self.caches[shard] = self.queue.submit(
+                self._install, self.caches[shard], jnp.int32(row),
+                jnp.asarray(row_table), jnp.int32(shared_len))
         tail = req.prompt[shared_len:]
         req.prefill_len, req.shared_len = len(tail), shared_len
         # retirement at admission (max_new == 1) must not release the
@@ -617,28 +631,34 @@ class Server:
                           tail, slot_idx: int | None = None,
                           defer_retire: bool = False) -> bool:
         p = len(tail)
-        toks = np.zeros((self.mb, _bucket(p)), np.int32)
-        toks[row, :p] = tail
-        sl = np.zeros((self.mb,), np.int32)
-        sl[row] = p
-        idx = slot_idx if slot_idx is not None else shard * self.mb + row
-        out = self._submit("prefill", self._prefill, self.params,
-                           jnp.asarray(toks), self.caches[shard],
-                           jnp.asarray(sl))
-        if out is None:              # retries exhausted
-            self._recover(req, idx, "prefill_failed")
-            return False
-        logits, self.caches[shard] = out
-        row_logits = logits[row]
-        if not bool(jnp.isfinite(row_logits).all()):
-            # poisoned prefill: only this request is damaged — the cache
-            # writes themselves landed, but its seed token is garbage
-            self._recover(req, idx, "nan_logits")
-            return False
-        # the prefill's final logits predict the first new token
-        self._append(req, idx, int(jnp.argmax(row_logits)),
-                     defer_retire=defer_retire)
-        return True
+        with obs.span("server.prefill", rid=req.rid, tail=p,
+                      shared=req.shared_len):
+            toks = np.zeros((self.mb, _bucket(p)), np.int32)
+            toks[row, :p] = tail
+            sl = np.zeros((self.mb,), np.int32)
+            sl[row] = p
+            idx = slot_idx if slot_idx is not None \
+                else shard * self.mb + row
+            out = self._submit("prefill", self._prefill, self.params,
+                               jnp.asarray(toks), self.caches[shard],
+                               jnp.asarray(sl))
+            if out is None:              # retries exhausted
+                self._recover(req, idx, "prefill_failed")
+                return False
+            logits, self.caches[shard] = out
+            with obs.span("server.prefill_readback"):
+                row_logits = logits[row]
+                finite = bool(jnp.isfinite(row_logits).all())
+                # the prefill's final logits predict the first new token
+                tok = int(jnp.argmax(row_logits)) if finite else None
+            if tok is None:
+                # poisoned prefill: only this request is damaged — the
+                # cache writes themselves landed, but its seed token is
+                # garbage
+                self._recover(req, idx, "nan_logits")
+                return False
+            self._append(req, idx, tok, defer_retire=defer_retire)
+            return True
 
     # ---------------------------------------------------------- retire
     def _release_slot(self, slot: int):
@@ -683,7 +703,8 @@ class Server:
         request was *consumed*: it is decoding in a slot, or it already
         retired at admission with a ``finish_reason`` (shed, rejected,
         finished) — check ``req.done``."""
-        return self.admit(req)
+        with obs.span("server.submit", rid=req.rid):
+            return self.admit(req)
 
     def poll(self, req: Request) -> list[int]:
         """Tokens generated since the last poll (the streaming delta).
@@ -793,7 +814,30 @@ class Server:
         retries routes its active requests into recovery, poisoned
         (non-finite) logits retire only their own slot, and the health
         state machine is advanced from the tick's fault/deferral counts.
+
+        While spans record (:mod:`repro.obs`), the tick's span carries the
+        page counts of :meth:`_page_counts`.
         """
+        attrs = self._page_counts() if obs.recording() else {}
+        with obs.span("server.tick", **attrs):
+            return self._tick()
+
+    def _page_counts(self) -> dict:
+        """``pages_reserved``: pages allocated from the pools (slot tables,
+        tree and holds); ``pages_unwritten``: pages of live slots' tables
+        past the last one holding a cached token."""
+        if not self.paged:
+            return {}
+        unwritten = 0
+        for req, table in zip(self.slots, self.slot_pages):
+            if req is None or table is None:
+                continue
+            cached = len(req.prompt) + len(req.out) - 1 if req.out else 0
+            unwritten += len(table) - -(-cached // self.page_size)
+        return {"pages_reserved": self.pages_in_use,
+                "pages_unwritten": unwritten}
+
+    def _tick(self) -> bool:
         t0 = time.perf_counter()
         self._tick_begin()
         inflight = self._decode_dispatch()
@@ -810,11 +854,12 @@ class Server:
         """Advance the serving clock and run the per-tick control work:
         expired pressure holds, pressure injection, recovery
         re-admission, deadline enforcement."""
-        self.clock += 1
-        self._expire_pressure()
-        self._inject_pressure()
-        self._readmit_recoveries()
-        self._deadline_sweep()
+        with obs.span("server.tick_control"):
+            self.clock += 1
+            self._expire_pressure()
+            self._inject_pressure()
+            self._readmit_recoveries()
+            self._deadline_sweep()
 
     def _decode_dispatch(self) -> list[tuple[int, jax.Array, np.ndarray]]:
         """Fire-and-forget one decode step per active shard; returns
@@ -856,9 +901,10 @@ class Server:
         """Token readback — the tick's only sync point.  Poisoned
         (non-finite) rows retire only their own slot."""
         for shard, logits, active in inflight:
-            lg = logits[:, 0]
-            finite = np.asarray(jnp.isfinite(lg).all(axis=-1))
-            nxt = np.asarray(jnp.argmax(lg, axis=-1))
+            with obs.span("server.decode_readback"):
+                lg = logits[:, 0]
+                finite = np.asarray(jnp.isfinite(lg).all(axis=-1))
+                nxt = np.asarray(jnp.argmax(lg, axis=-1))
             for j in range(self.mb):
                 if not active[j]:
                     continue
