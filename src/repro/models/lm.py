@@ -28,7 +28,7 @@ from repro.models import xlstm as xl
 from repro.models.common import apply_dense, apply_norm, embed_init, \
     make_positions, norm_init
 from repro.models.transformer import (
-    AttnArgs, block_apply, block_init,
+    POOL_KEYS, AttnArgs, _is_paged, block_apply, block_init,
     init_kv_cache, install_kv_pages, migrate_kv_pages, reset_kv_slot,
     stack_init,
 )
@@ -520,6 +520,14 @@ def decode_step(params, token, caches, cfg: ArchConfig, *, seq_lens=None):
     enabling ragged prompts and idle slots in a serving batch.  Logits at
     positions ``>= seq_lens[b]`` of row b are garbage and must be ignored
     by the caller (``prefill_into`` gathers each row's last valid one).
+
+    A paged pool (dense/moe/vlm) is updated in place: the layer scan
+    carries the stacked pool leaves (pages of the lane-dense rows that
+    ``init_kv_cache`` describes) with the layer index, and each layer
+    writes and then reads them at ``[layer, page]``, so the step holds no
+    other pool-sized buffer.  Callers that jit the step donate the caches
+    (``serving_fns(donate=True)``); an undonated pool is copied once a
+    call, since the caller's array must survive.
     """
     fam = cfg.family
     x = _embed(params, token, cfg)
@@ -530,18 +538,29 @@ def decode_step(params, token, caches, cfg: ArchConfig, *, seq_lens=None):
     keep = None if seq_lens is None else seq_lens > 0
     if fam in ("dense", "moe", "vlm"):
         a = attn_args(cfg)
+        self_c = caches["self"]
+        # a paged pool rides in the carry, with the layer index; the small
+        # per-slot leaves (and a dense cache whole) are scanned xs / ys
+        pool = ({k: v for k, v in self_c.items() if k in POOL_KEYS}
+                if _is_paged(self_c) else {})
+        per_layer = {k: v for k, v in self_c.items() if k not in pool}
 
-        def body(x, inp):
+        def body(carry, inp):
+            x, pool, layer = carry
             lp, cache = inp
-            c = {"self": cache}
-            x, nc, _ = block_apply(lp, x, a, caches=c, act=cfg.act,
-                                   norm=cfg.norm, moe_cfg=cfg.moe,
-                                   compute_dtype=_cdt(cfg),
+            c = {**cache, **pool, "layer": layer} if pool else cache
+            x, nc, _ = block_apply(lp, x, a, caches={"self": c},
+                                   act=cfg.act, norm=cfg.norm,
+                                   moe_cfg=cfg.moe, compute_dtype=_cdt(cfg),
                                    seq_lens=seq_lens)
-            return x, nc["self"]
+            nc = nc["self"]
+            return ((x, {k: nc[k] for k in pool}, layer + 1),
+                    {k: nc[k] for k in cache})
 
-        x, new_self = _scan(body, x, (params["layers"], caches["self"]))
-        new_caches = {"self": new_self}
+        (x, pool, _), per_layer = _scan(
+            body, (x, pool, jnp.zeros((), jnp.int32)),
+            (params["layers"], per_layer))
+        new_caches = {"self": {**per_layer, **pool}}
     elif fam == "hybrid":
         a = attn_args(cfg, window=cfg.sliding_window)
         shared = params["shared"]

@@ -87,7 +87,14 @@ def init_kv_cache(batch: int, max_len: int, a: AttnArgs, dtype,
     **Paged** (``page_size > 0``) — slots share a fixed pool of
     ``page_size``-token pages and address them through a page table:
 
-      * ``k_pages`` / ``v_pages``  ``(n_pages, page_size, n_kv, hd)``
+      * ``k_pages`` / ``v_pages``  ``(n_pages, *_page_shape(page_size,
+        n_kv * hd))`` — a page's tokens' ``(n_kv, hd)`` blocks in position
+        order, held as lane-dense rows: one row per token where ``n_kv *
+        hd`` is a multiple of the TPU's 128 lanes, else the whole page as
+        one row.  In the chip's default layout of a ``(n_pages, P, n_kv,
+        hd)`` pool the page index would lie on the lanes, where neither the
+        token write nor the page gather can use it, so every step would
+        relay the whole pool out and back
       * ``page_table``  ``(batch, ceil(max_len / page_size))`` int32 —
         entry ``j`` of row ``b`` is the pool page holding row ``b``'s
         absolute positions ``[j * page_size, (j + 1) * page_size)``;
@@ -113,6 +120,13 @@ def init_kv_cache(batch: int, max_len: int, a: AttnArgs, dtype,
     independently, and be reset and reused without touching their
     neighbours — the substrate for continuous batching.
 
+    A model's layer stack of paged caches (``lm.init_caches``) is updated
+    in place: ``lm.decode_step`` carries the stacked pool leaves
+    (``POOL_KEYS``) through its layer loop, and each layer writes and
+    reads them at its own index (``_paged_cache_update``).  Callers that
+    jit a step donate the caches, or the step copies the pool once a call
+    to keep the caller's array.
+
     ``ring=True`` -> sliding-window ring buffer (dense only).
     ``quant=True`` -> int8 K/V with per-(token, head) f32 scales: halves
     the decode memory term (decode reads the whole cache every step)."""
@@ -124,19 +138,17 @@ def init_kv_cache(batch: int, max_len: int, a: AttnArgs, dtype,
         n_slot_pages = -(-max_len // page_size)
         if not n_pages:
             n_pages = batch * n_slot_pages
+        kv = (n_pages,) + _page_shape(page_size, a.n_kv * a.hd)
         cache = {
-            "k_pages": jnp.zeros((n_pages, page_size, a.n_kv, a.hd),
-                                 kv_dtype),
-            "v_pages": jnp.zeros((n_pages, page_size, a.n_kv, a.hd),
-                                 kv_dtype),
+            "k_pages": jnp.zeros(kv, kv_dtype),
+            "v_pages": jnp.zeros(kv, kv_dtype),
             "page_table": jnp.full((batch, n_slot_pages), -1, jnp.int32),
             "len": jnp.zeros((batch,), jnp.int32),
         }
         if quant:
-            cache["k_scale_pages"] = jnp.zeros(
-                (n_pages, page_size, a.n_kv), jnp.float32)
-            cache["v_scale_pages"] = jnp.zeros(
-                (n_pages, page_size, a.n_kv), jnp.float32)
+            sc = (n_pages,) + _page_shape(page_size, a.n_kv)
+            cache["k_scale_pages"] = jnp.zeros(sc, jnp.float32)
+            cache["v_scale_pages"] = jnp.zeros(sc, jnp.float32)
         return cache
     size = min(max_len, a.sliding_window) if (ring and a.sliding_window) \
         else max_len
@@ -152,6 +164,16 @@ def init_kv_cache(batch: int, max_len: int, a: AttnArgs, dtype,
         cache["k_scale"] = jnp.zeros((batch, size, a.n_kv), jnp.float32)
         cache["v_scale"] = jnp.zeros((batch, size, a.n_kv), jnp.float32)
     return cache
+
+
+# the leaves of a paged cache that hold the page pool (the rest are per-slot)
+POOL_KEYS = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages")
+
+
+def _page_shape(page_size: int, width: int) -> tuple:
+    """One pool page of ``page_size`` tokens of ``width`` numbers: a row
+    per token where ``width`` fills whole 128-lane rows, else one row."""
+    return (page_size, width) if width % 128 == 0 else (page_size * width,)
 
 
 def _is_paged(cache) -> bool:
@@ -216,7 +238,7 @@ def migrate_kv_pages(src_cache, dst_cache, src_pages, dst_pages):
     content, so last-write-wins is deterministic.
     """
     out = dict(dst_cache)
-    for key in ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages"):
+    for key in POOL_KEYS:
         if key in dst_cache:
             out[key] = dst_cache[key].at[dst_pages].set(
                 src_cache[key][src_pages], mode="drop")
@@ -370,65 +392,88 @@ def _paged_cache_update(cache, k_new, v_new, posq, token_valid, new_len,
     """Append ``k_new``/``v_new`` through the page table and build the
     position-ordered attention view.
 
-    Write: token (b, i) at absolute position ``posq[b, i]`` lands in pool
-    page ``page_table[b, posq // P]`` at offset ``posq % P``.  Invalid
-    tokens (beyond ``seq_lens``, beyond the table, or aimed at an
-    unassigned ``-1`` entry) are redirected to page id ``n_pages`` and
-    dropped by the scatter — a slot can never write outside its own
-    assigned pages, which is what keeps shared (refcount > 1) pages
-    immutable.
+    The pool leaves (``POOL_KEYS``, pages of ``_page_shape``) are one
+    layer's ``(n_pages, *page)``, or, when the cache also holds a
+    ``layer`` index, the whole layer-stacked ``(L, n_pages, *page)`` pool
+    that ``lm.decode_step`` carries through its layer loop: the write and
+    the read then address ``[layer, page]`` in it, so the loop updates the
+    (donated) pool in place and no layer's pool is sliced out or stacked
+    again.
 
-    Read: gathering the slot's table rebuilds a contiguous
-    ``(B, n_slot_pages * P, KV, hd)`` view in which view index == absolute
-    position, so validity is ``t <= posq`` (causal) and ``t < new_len``
-    (written); unassigned table entries gather page 0 but are masked by
-    the length test.
+    Write: token (b, i) at absolute position ``posq[b, i]`` lands in pool
+    page ``page_table[b, posq // P]`` at offset ``posq % P``.  The pages
+    a row's new tokens fall in are gathered, the tokens written into them,
+    and the pages written back whole: the chip scatters whole rows of its
+    tiles at once but a part of a row one token at a time.  Invalid tokens
+    (beyond ``seq_lens``, beyond the table, or aimed at an unassigned
+    ``-1`` entry) are dropped, and a page holding no valid new token is
+    not written back — a slot can never write outside its own assigned
+    pages, which is what keeps shared (refcount > 1) pages immutable.  A
+    page written back holds the bytes it held wherever no token landed,
+    so the pool is the one a token-by-token write gives.
+
+    Read, after the write: gathering the slot's table rebuilds a
+    contiguous ``(B, n_slot_pages * P, KV, hd)`` view in which view index
+    == absolute position, so validity is ``t <= posq`` (causal) and
+    ``t < new_len`` (written); unassigned table entries gather page 0 but
+    are masked by the length test.
 
     Returns ``(new_cache, k_read, v_read, valid)`` with f32 read views.
     """
-    pool_k, pool_v = cache["k_pages"], cache["v_pages"]
-    n_pages, page, n_kv, hd = pool_k.shape
+    lead = (cache["layer"],) if "layer" in cache else ()
     pt = cache["page_table"]                       # (B, NP)
     b, np_ = pt.shape
-    page_idx = jnp.clip(posq // page, 0, np_ - 1)
-    pid = jnp.take_along_axis(pt, page_idx, axis=1)     # (B, S)
-    off = posq % page
-    keep = token_valid & (posq < np_ * page) & (pid >= 0)
-    # invalid writes aim at page `n_pages` and are dropped by the scatter
-    pid = jnp.where(keep, pid, n_pages)
-    quant = "k_scale_pages" in cache
-    if quant:
+    s, n_kv, hd = k_new.shape[1:]
+    pool_k = cache["k_pages"]
+    n_pages = pool_k.shape[len(lead)]
+    page = math.prod(pool_k.shape[len(lead) + 1:]) // (n_kv * hd)
+    # the pages row b's new tokens can fall in, from that of its first one
+    first = posq[:, :1] // page
+    n_touch = min(np_, (s + page - 2) // page + 1)
+    touch = first + jnp.arange(n_touch, dtype=jnp.int32)[None, :]
+    tpid = jnp.take_along_axis(pt, jnp.clip(touch, 0, np_ - 1), axis=1)
+    # a page that holds no valid new token aims at `n_pages`: dropped
+    written = ((touch < np_) & (touch * page < new_len[:, None])
+               & (posq[:, :1] < new_len[:, None]) & (tpid >= 0))
+    tpid = jnp.where(written, tpid, n_pages)
+    # token (b, i) within its row's touched pages; invalid ones aim past
+    # their end and are dropped
+    at = jnp.where(token_valid, posq - first * page, n_touch * page)
+    rows = jnp.arange(b, dtype=jnp.int32)[:, None]
+    safe_pt = jnp.where(pt < 0, 0, pt)
+
+    def write(key, new):
+        pool = cache[key]
+        tail = new.shape[2:]
+        pages = pool[lead + (jnp.minimum(tpid, n_pages - 1),)]
+        pages = pages.reshape((b, n_touch * page) + tail).at[rows, at].set(
+            cast(new, pool.dtype), mode="drop").reshape(pages.shape)
+        return pool.at[lead + (tpid,)].set(pages, mode="drop")
+
+    def read(pool, tail):
+        return pool[lead + (safe_pt,)].reshape((b, np_ * page) + tail)
+
+    if "k_scale_pages" in cache:
         k_q, k_s = _kv_quantize(k_new)
         v_q, v_s = _kv_quantize(v_new)
-        kc = pool_k.at[pid, off].set(k_q, mode="drop")
-        vc = pool_v.at[pid, off].set(v_q, mode="drop")
-        k_sc = cache["k_scale_pages"].at[pid, off].set(k_s, mode="drop")
-        v_sc = cache["v_scale_pages"].at[pid, off].set(v_s, mode="drop")
-        extra = {"k_scale_pages": k_sc, "v_scale_pages": v_sc}
+        new = {"k_pages": write("k_pages", k_q),
+               "v_pages": write("v_pages", v_q),
+               "k_scale_pages": write("k_scale_pages", k_s),
+               "v_scale_pages": write("v_scale_pages", v_s)}
+        k_read = _kv_dequant(read(new["k_pages"], (n_kv, hd)),
+                             read(new["k_scale_pages"], (n_kv,)))
+        v_read = _kv_dequant(read(new["v_pages"], (n_kv, hd)),
+                             read(new["v_scale_pages"], (n_kv,)))
     else:
-        kc = pool_k.at[pid, off].set(cast(k_new, pool_k.dtype),
-                                     mode="drop")
-        vc = pool_v.at[pid, off].set(cast(v_new, pool_v.dtype),
-                                     mode="drop")
-        extra = {}
-    # gather view: (B, NP, P, KV, hd) -> (B, NP * P, KV, hd)
-    safe_pt = jnp.where(pt < 0, 0, pt)
-    k_view = jnp.take(kc, safe_pt, axis=0).reshape(b, np_ * page, n_kv, hd)
-    v_view = jnp.take(vc, safe_pt, axis=0).reshape(b, np_ * page, n_kv, hd)
-    if quant:
-        k_sv = jnp.take(k_sc, safe_pt, axis=0).reshape(b, np_ * page, n_kv)
-        v_sv = jnp.take(v_sc, safe_pt, axis=0).reshape(b, np_ * page, n_kv)
-        k_read = _kv_dequant(k_view, k_sv)
-        v_read = _kv_dequant(v_view, v_sv)
-    else:
-        k_read = k_view.astype(jnp.float32)
-        v_read = v_view.astype(jnp.float32)
+        new = {"k_pages": write("k_pages", k_new),
+               "v_pages": write("v_pages", v_new)}
+        k_read = read(new["k_pages"], (n_kv, hd)).astype(jnp.float32)
+        v_read = read(new["v_pages"], (n_kv, hd)).astype(jnp.float32)
     t_pos = jnp.arange(np_ * page, dtype=jnp.int32)[None, None, :]
     valid = (t_pos <= posq[:, :, None]) & (t_pos < new_len[:, None, None])
     if a.sliding_window is not None:
         valid &= posq[:, :, None] - t_pos < a.sliding_window
-    new_cache = {**cache, "k_pages": kc, "v_pages": vc, "len": new_len,
-                 **extra}
+    new_cache = {**cache, **new, "len": new_len}
     return new_cache, k_read, v_read, valid
 
 

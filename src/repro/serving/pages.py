@@ -1,9 +1,8 @@
 """Refcounting page allocator for the paged KV cache.
 
 One ``PagePool`` instance governs one cache pytree's page id space: the
-ids it hands out index the leading axis of every layer's
-``(n_pages, page_size, n_kv, hd)`` pool array (page assignment is
-layer-uniform, exactly like the per-slot ``len`` vector).
+ids it hands out index the page axis of every layer's pool array (page
+assignment is layer-uniform, exactly like the per-slot ``len`` vector).
 
 Refcount invariants — the ones the eviction test enforces:
 

@@ -3,8 +3,16 @@ PrefixTree match/insert/evict semantics, and agreement of the paged cache
 layout with the dense one at the ``lm`` level (including int8 KV
 quantization and shared-prefix tail prefill).  The two layouts are
 different programs, so their logits agree within the float32 tolerance
-of ``repro.models.reference``, not bit for bit.
+of ``repro.models.reference``, not bit for bit.  The stacked pool that the
+serving steps update in place is held to the per-layer, token-by-token
+update it replaced, bit for bit, and its compiled programs to holding no
+copy of the pool.
 """
+import contextlib
+import dataclasses
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -231,3 +239,251 @@ def test_paged_reset_slot_clears_table_not_pool(smollm):
     assert (np.asarray(c["page_table"])[:, 1] == -1).all()
     assert (np.asarray(c["page_table"])[:, 0, :2] == [0, 1]).all()
     np.testing.assert_array_equal(np.asarray(c["k_pages"]), before)
+
+
+# ================================ in-place pool == today's per-layer rule ==
+def _token_paged_update(cache, k_new, v_new, posq, token_valid, new_len, a):
+    """The single-layer paged write and read as they were before the pool
+    rode in the layer loop's carry, on one layer's pool: every valid token
+    scattered into its page at its offset, then the slot's pages
+    gathered."""
+    from repro.models.transformer import _kv_dequant, _kv_quantize
+    pt = cache["page_table"]
+    b, np_ = pt.shape
+    n_kv, hd = k_new.shape[2:]
+    n_pages = cache["k_pages"].shape[0]
+    page = math.prod(cache["k_pages"].shape[1:]) // (n_kv * hd)
+    pid = jnp.take_along_axis(pt, jnp.clip(posq // page, 0, np_ - 1), 1)
+    keep = token_valid & (posq < np_ * page) & (pid >= 0)
+    pid, off = jnp.where(keep, pid, n_pages), posq % page
+    safe = jnp.where(pt < 0, 0, pt)
+    out = {}
+
+    def write_read(key, new):
+        pool = cache[key].reshape((n_pages, page) + new.shape[2:])
+        pool = pool.at[pid, off].set(new.astype(pool.dtype), mode="drop")
+        out[key] = pool.reshape(cache[key].shape)
+        return jnp.take(pool, safe, axis=0).reshape(
+            (b, np_ * page) + new.shape[2:])
+
+    if "k_scale_pages" in cache:
+        (k_q, k_s), (v_q, v_s) = _kv_quantize(k_new), _kv_quantize(v_new)
+        k_read = _kv_dequant(write_read("k_pages", k_q),
+                             write_read("k_scale_pages", k_s))
+        v_read = _kv_dequant(write_read("v_pages", v_q),
+                             write_read("v_scale_pages", v_s))
+    else:
+        k_read = write_read("k_pages", k_new).astype(jnp.float32)
+        v_read = write_read("v_pages", v_new).astype(jnp.float32)
+    t = jnp.arange(np_ * page)[None, None, :]
+    valid = (t <= posq[:, :, None]) & (t < new_len[:, None, None])
+    return {**cache, **out, "len": new_len}, k_read, v_read, valid
+
+
+def _layer_loop_oracle(params, tokens, caches, cfg, seq_lens):
+    """The paged step as it was before the pool rode in the loop carry: a
+    loop over the layers, each given its own slice of every cache leaf as
+    a scanned input, written token by token (``_token_paged_update``) and
+    stacked again as a scanned output.  Returns ``(logits, caches)``.
+
+    The loop is ``lm._scan``, as the step's own: a ``lax.scan``, or a
+    python loop under ``flags.UNROLL``.  A python loop checked against the
+    scanned step would compare two program structures, and the CPU
+    backend rounds a bfloat16 intermediate in one and not the other."""
+    from repro.models import transformer
+    a = lm.attn_args(cfg)
+
+    def layer(x, inp):
+        lp, c = inp
+        x, nc, _ = transformer.block_apply(
+            lp, x, a, caches={"self": c}, act=cfg.act, norm=cfg.norm,
+            moe_cfg=cfg.moe, compute_dtype=lm._cdt(cfg), seq_lens=seq_lens)
+        return x, nc["self"]
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(transformer, "_paged_cache_update", _token_paged_update)
+        x, new = lm._scan(layer, lm._embed(params, tokens, cfg),
+                          (params["layers"], caches["self"]))
+    return lm._unembed(params, x, cfg), {"self": new}
+
+
+def _step_case(cfg, params, case):
+    """(caches, tokens, seq_lens) of one step after a ragged prefill that
+    leaves rows mid-page (4-token pages): a decode of every row, a
+    16-token tail prefill over another row's two full prefix pages while
+    the other rows idle, or a ragged step with an idle row whose widest
+    row writes into three pages."""
+    B, P, max_len = 3, 4, 40
+    n_slot = max_len // P
+    caches = lm.init_caches(cfg, B, max_len, paged=True, page_size=P,
+                            n_pages=B * n_slot + 4)
+    for b in range(B):
+        caches = lm.install_pages(caches, b, _seq_table(b * n_slot, n_slot),
+                                  0, cfg)
+    rng = np.random.default_rng(11)
+    first = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, 12)), jnp.int32)
+    _, caches = jax.jit(lambda c: lm.prefill_into(
+        params, first, c, cfg,
+        seq_lens=jnp.asarray([12, 9, 10], jnp.int32)))(caches)
+    if case == "decode":
+        tok = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, 1)), jnp.int32)
+        return caches, tok, jnp.ones((B,), jnp.int32)
+    if case == "tail":
+        # row 2 starts over row 0's first two (full) pages: 8 shared tokens
+        row = np.full(n_slot, -1, np.int32)
+        row[:2] = [0, 1]
+        row[2:7] = np.arange(2 * n_slot, 2 * n_slot + 5)
+        caches = lm.install_pages(caches, 2, jnp.asarray(row), 8, cfg)
+        tok = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, 16)),
+                          jnp.int32)
+        return caches, tok, jnp.asarray([0, 0, 16], jnp.int32)
+    # row 0 writes positions 12..16 (pages 3, 4), row 1 9..16 (2, 3, 4)
+    tok = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, 8)), jnp.int32)
+    return caches, tok, jnp.asarray([5, 8, 0], jnp.int32)
+
+
+# An int8 pool's float32 scales, and the float32 logits of its config, are
+# held to an absolute 1e-6, not bit for bit: the CPU backend fuses each
+# program's projections and quantisation (``_kv_quantize``) into different
+# kernels and may keep a float32 intermediate unrounded in one of them, so
+# a scale's last bits differ and the difference carries through the layers
+# (at most 3.1e-8 on scales of ~1e-2 and 6.7e-7 on logits, measured on
+# these cases).  Its int8 values, and the bf16 pool, cache and logits of
+# the bf16 config, are held bit for bit.
+INT8_TOL = 1e-6
+
+
+@pytest.mark.parametrize("row", ["page", "token"])
+@pytest.mark.parametrize("case", ["decode", "tail", "ragged"])
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_in_place_pool_matches_per_layer_update(pool, case, row):
+    """The donated serving steps, which carry the stacked pool through the
+    layer loop and write whole pages in place, give the logits and every
+    cache leaf of the same loop over per-layer slices of the pool written
+    token by token; with the layers scanned and with them unrolled.  Two
+    KV heads: ``n_kv * hd`` 32 holds a page as one row, 128 a row per
+    token."""
+    from repro.launch.serve import serving_fns
+    from repro.models import flags
+    cfg = dataclasses.replace(reduce(configs.get("smollm_135m")), n_layers=3,
+                              head_dim=16 if row == "page" else 64)
+    cfg = (dataclasses.replace(cfg, compute_dtype="bfloat16")
+           if pool == "bf16" else dataclasses.replace(cfg, kv_quant=True))
+    params = lm.init_params(cfg, jax.random.PRNGKey(3))[0]
+    caches, tok, sl = _step_case(cfg, params, case)
+    assert caches["self"]["k_pages"].ndim == (3 if row == "page" else 4)
+    for unroll in (False, True):
+        with flags.unrolled() if unroll else contextlib.nullcontext():
+            want, want_c = jax.jit(
+                lambda c: _layer_loop_oracle(params, tok, c, cfg, sl))(caches)
+            prefill, decode = serving_fns(cfg, donate=True)
+            got, got_c = decode(params, tok, jax.tree_util.tree_map(
+                jnp.copy, caches), sl)
+            got_last, got_c2 = prefill(params, tok, jax.tree_util.tree_map(
+                jnp.copy, caches), sl)
+        want_last = jnp.take_along_axis(
+            want, jnp.maximum(sl - 1, 0)[:, None, None], axis=1)[:, 0]
+        tol = 0 if pool == "bf16" else INT8_TOL
+        for g, w in ((got, want), (got_last, want_last)):
+            np.testing.assert_allclose(np.asarray(g, np.float32),
+                                       np.asarray(w, np.float32),
+                                       rtol=0, atol=tol)
+        for gc in (got_c, got_c2):
+            assert sorted(gc["self"]) == sorted(want_c["self"])
+            for key, leaf in want_c["self"].items():
+                assert gc["self"][key].dtype == leaf.dtype, key
+                np.testing.assert_allclose(
+                    np.asarray(gc["self"][key]), np.asarray(leaf), rtol=0,
+                    atol=tol if leaf.dtype == jnp.float32 else 0,
+                    err_msg=key)
+
+
+@pytest.mark.parametrize("row", ["page", "token"])
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_layer_indexed_update_matches_one_layer_pool(pool, row):
+    """``_paged_cache_update`` at ``[layer, page]`` of a stacked pool, and
+    on one layer's pool with no index, gives from the same new keys and
+    values the pool and the views of today's token-by-token write on that
+    layer's slice (row 0 from a page start, row 1 idle, row 2 from
+    mid-page across three pages)."""
+    from repro.models.transformer import _paged_cache_update
+    cfg = dataclasses.replace(reduce(configs.get("smollm_135m")), n_layers=3,
+                              head_dim=16 if row == "page" else 64,
+                              kv_quant=pool == "int8",
+                              compute_dtype="bfloat16")
+    a = lm.attn_args(cfg)
+    caches = lm.init_caches(cfg, 3, 40, paged=True, page_size=4, n_pages=34)
+    for b in range(3):
+        caches = lm.install_pages(caches, b, _seq_table(b * 10, 10), 0, cfg)
+    c = jax.tree_util.tree_map(lambda v: v + 1,
+                               caches["self"])   # pages hold old bytes
+    new = jnp.asarray(np.random.default_rng(2).normal(
+        size=(3, 8, 2, cfg.hd)), jnp.bfloat16)
+    posq = jnp.asarray([[0], [5], [3]], jnp.int32) + jnp.arange(8)
+    valid = jnp.arange(8)[None] < jnp.asarray([[8], [0], [8]])
+    new_len = posq[:, 0] + valid.sum(1).astype(jnp.int32)
+
+    def per_layer(update, i, one):
+        nc, k_read, v_read, _ = update(one, new * (i + 1), -new, posq, valid,
+                                       new_len, a)
+        return {k: nc[k] for k in nc if "pages" in k}, k_read, v_read
+
+    def stacked(c):
+        pool = {k: v for k, v in c.items() if "pages" in k}
+        reads = []
+        for i in range(3):
+            one = {"page_table": c["page_table"][i], "len": c["len"][i],
+                   **pool, "layer": jnp.int32(i)}
+            pool, *r = per_layer(_paged_cache_update, i, one)
+            reads.append(r)
+        return pool, reads
+
+    def sliced(update, c):
+        pools, reads = [], []
+        for i in range(3):
+            one = jax.tree_util.tree_map(lambda v: v[i], c)
+            p, *r = per_layer(update, i, one)
+            pools.append(p)
+            reads.append(r)
+        return jax.tree_util.tree_map(lambda *v: jnp.stack(v), *pools), reads
+
+    want = jax.jit(lambda c: sliced(_token_paged_update, c))(c)
+    for got in (jax.jit(stacked)(c),
+                jax.jit(lambda c: sliced(_paged_cache_update, c))(c)):
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def pool_copies(hlo: str, pool_shape) -> list[str]:
+    """The ``copy`` ops of an optimised HLO text whose result holds as many
+    elements as the stacked pool ``pool_shape`` or as one layer's pool,
+    whatever their shape."""
+    sizes = {math.prod(pool_shape), math.prod(pool_shape[1:])}
+    return [ln for ln in hlo.splitlines()
+            if (m := re.search(r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(", ln))
+            and math.prod(map(int, m.group(1).split(","))) in sizes]
+
+
+@pytest.mark.parametrize("unroll", [False, True])
+def test_donated_serving_steps_never_copy_the_pool(unroll):
+    """The compiled, donated prefill and decode programs hold no copy of
+    the stacked pool or of one layer's pool: each layer writes the pool
+    the loop carries, in the donated buffer; also with the layer loop
+    unrolled (``flags.UNROLL``)."""
+    from repro.launch.serve import serving_fns
+    from repro.models import flags
+    cfg = reduce(configs.get("smollm_135m"))
+    params = jax.eval_shape(lambda: lm.init_params(
+        cfg, jax.random.PRNGKey(0))[0])
+    # 40 pages of 4 against 4 rows of 8 x 4 tokens: no token block and no
+    # view holds as many elements as a pool
+    caches = jax.eval_shape(lambda: lm.init_caches(
+        cfg, 4, 32, paged=True, page_size=4, n_pages=40))
+    sl = jax.ShapeDtypeStruct((4,), jnp.int32)
+    with flags.unrolled() if unroll else contextlib.nullcontext():
+        for fn, width in zip(serving_fns(cfg, donate=True), (8, 1)):
+            tok = jax.ShapeDtypeStruct((4, width), jnp.int32)
+            hlo = fn.lower(params, tok, caches, sl).compile().as_text()
+            copies = pool_copies(hlo, caches["self"]["k_pages"].shape)
+            assert not copies, copies
