@@ -825,17 +825,22 @@ class Server:
     def _page_counts(self) -> dict:
         """``pages_reserved``: pages allocated from the pools (slot tables,
         tree and holds); ``pages_unwritten``: pages of live slots' tables
-        past the last one holding a cached token."""
+        past the last one holding a cached token; ``pages_read``: pages the
+        tick's decode step reads, ``ceil((cached + 1) / page_size)`` for
+        each slot it feeds (what the paged-decode kernel reads of a
+        layer's pool)."""
         if not self.paged:
             return {}
-        unwritten = 0
+        unwritten = read = 0
         for req, table in zip(self.slots, self.slot_pages):
             if req is None or table is None:
                 continue
             cached = len(req.prompt) + len(req.out) - 1 if req.out else 0
             unwritten += len(table) - -(-cached // self.page_size)
+            if req.out and not req.done:
+                read += -(-(cached + 1) // self.page_size)
         return {"pages_reserved": self.pages_in_use,
-                "pages_unwritten": unwritten}
+                "pages_unwritten": unwritten, "pages_read": read}
 
     def _tick(self) -> bool:
         t0 = time.perf_counter()

@@ -184,7 +184,19 @@ def _sinusoid(seq: int, d: int, dtype):
 
 
 def _embed(params, tokens, cfg):
-    e = jnp.take(params["embed"]["w"], tokens, axis=0)
+    w = params["embed"]["w"]
+    if tokens.shape[1] == 1 and w.shape[1] % 128:
+        # A decode step's few rows as a one-hot product, exact at any
+        # dtype.  The chip lays out a table whose width does not fill
+        # whole 128-lane rows with the vocabulary on the lanes, where a row
+        # gather first copies the whole table into rows; the product reads
+        # the table as it lies.
+        e = jnp.einsum("bsv,vd->bsd", jax.nn.one_hot(tokens, w.shape[0],
+                                                     dtype=w.dtype), w,
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+    else:
+        e = jnp.take(w, tokens, axis=0)
     return e.astype(_cdt(cfg))
 
 

@@ -17,6 +17,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.paged_decode import ops as paged_decode_ops
 from repro.models.common import (
     apply_dense, apply_norm, cast, dense_init, gelu, mrope, norm_init,
     rope, swiglu_combine,
@@ -88,13 +89,16 @@ def init_kv_cache(batch: int, max_len: int, a: AttnArgs, dtype,
     ``page_size``-token pages and address them through a page table:
 
       * ``k_pages`` / ``v_pages``  ``(n_pages, *_page_shape(page_size,
-        n_kv * hd))`` — a page's tokens' ``(n_kv, hd)`` blocks in position
-        order, held as lane-dense rows: one row per token where ``n_kv *
-        hd`` is a multiple of the TPU's 128 lanes, else the whole page as
-        one row.  In the chip's default layout of a ``(n_pages, P, n_kv,
-        hd)`` pool the page index would lie on the lanes, where neither the
-        token write nor the page gather can use it, so every step would
-        relay the whole pool out and back
+        n_kv * hd, pad=not quant))`` — a page's tokens' ``(n_kv, hd)``
+        blocks in position order, held as lane-dense rows: one row per
+        token, ``n_kv * hd`` numbers padded with zeros to whole 128-lane
+        rows (an int8 pool pads nothing: a width that does not fill whole
+        rows holds the whole page as one row).  The chip lays out an
+        unpadded ``(n_pages, P, n_kv * hd)`` or ``(n_pages, P, n_kv, hd)``
+        pool with the page index on the lanes, where neither the token
+        write nor the page read can use it, so every step would relay the
+        whole pool out and back; the padded rows are what the paged-decode
+        kernel (``repro.kernels.paged_decode``) reads page by page
       * ``page_table``  ``(batch, ceil(max_len / page_size))`` int32 —
         entry ``j`` of row ``b`` is the pool page holding row ``b``'s
         absolute positions ``[j * page_size, (j + 1) * page_size)``;
@@ -138,7 +142,8 @@ def init_kv_cache(batch: int, max_len: int, a: AttnArgs, dtype,
         n_slot_pages = -(-max_len // page_size)
         if not n_pages:
             n_pages = batch * n_slot_pages
-        kv = (n_pages,) + _page_shape(page_size, a.n_kv * a.hd)
+        kv = (n_pages,) + _page_shape(page_size, a.n_kv * a.hd,
+                                      pad=not quant)
         cache = {
             "k_pages": jnp.zeros(kv, kv_dtype),
             "v_pages": jnp.zeros(kv, kv_dtype),
@@ -146,7 +151,7 @@ def init_kv_cache(batch: int, max_len: int, a: AttnArgs, dtype,
             "len": jnp.zeros((batch,), jnp.int32),
         }
         if quant:
-            sc = (n_pages,) + _page_shape(page_size, a.n_kv)
+            sc = (n_pages,) + _page_shape(page_size, a.n_kv, pad=False)
             cache["k_scale_pages"] = jnp.zeros(sc, jnp.float32)
             cache["v_scale_pages"] = jnp.zeros(sc, jnp.float32)
         return cache
@@ -170,9 +175,13 @@ def init_kv_cache(batch: int, max_len: int, a: AttnArgs, dtype,
 POOL_KEYS = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages")
 
 
-def _page_shape(page_size: int, width: int) -> tuple:
+def _page_shape(page_size: int, width: int, *, pad: bool) -> tuple:
     """One pool page of ``page_size`` tokens of ``width`` numbers: a row
-    per token where ``width`` fills whole 128-lane rows, else one row."""
+    per token, ``width`` padded to whole 128-lane rows where ``pad``;
+    unpadded, a ``width`` that does not fill whole rows holds the page as
+    one row."""
+    if pad:
+        return (page_size, -(-width // 128) * 128)
     return (page_size, width) if width % 128 == 0 else (page_size * width,)
 
 
@@ -389,8 +398,55 @@ def _xla_flash(q, k, v, scale, *, causal, window, q_chunk=512,
 
 def _paged_cache_update(cache, k_new, v_new, posq, token_valid, new_len,
                         a: AttnArgs):
-    """Append ``k_new``/``v_new`` through the page table and build the
-    position-ordered attention view.
+    """Append ``k_new``/``v_new`` through the page table
+    (``_paged_write``), then build the position-ordered attention view:
+    gathering the slot's table rebuilds a contiguous ``(B, n_slot_pages *
+    P, KV, hd)`` view in which view index == absolute position, so
+    validity is ``t <= posq`` (causal) and ``t < new_len`` (written);
+    unassigned table entries gather page 0 but are masked by the length
+    test.
+
+    Returns ``(new_cache, k_read, v_read, valid)`` with f32 read views.
+    """
+    new_cache = _paged_write(cache, k_new, v_new, posq, token_valid, new_len)
+    n_kv, hd = k_new.shape[2:]
+    lead = (cache["layer"],) if "layer" in cache else ()
+    pt = cache["page_table"]
+    safe_pt = jnp.where(pt < 0, 0, pt)
+
+    def read(key, feat):
+        pool = new_cache[key]
+        _, row = _page_rows(pool.shape[len(lead) + 1:], math.prod(feat))
+        view = pool[lead + (safe_pt,)].reshape(pt.shape[0], -1, row)
+        return view[..., :math.prod(feat)].reshape(view.shape[:2] + feat)
+
+    if "k_scale_pages" in cache:
+        k_read = _kv_dequant(read("k_pages", (n_kv, hd)),
+                             read("k_scale_pages", (n_kv,)))
+        v_read = _kv_dequant(read("v_pages", (n_kv, hd)),
+                             read("v_scale_pages", (n_kv,)))
+    else:
+        k_read = read("k_pages", (n_kv, hd)).astype(jnp.float32)
+        v_read = read("v_pages", (n_kv, hd)).astype(jnp.float32)
+    t_pos = jnp.arange(k_read.shape[1], dtype=jnp.int32)[None, None, :]
+    valid = (t_pos <= posq[:, :, None]) & (t_pos < new_len[:, None, None])
+    if a.sliding_window is not None:
+        valid &= posq[:, :, None] - t_pos < a.sliding_window
+    return new_cache, k_read, v_read, valid
+
+
+def _page_rows(page_shape, width: int) -> tuple[int, int]:
+    """``(tokens per page, numbers per row)`` of a pool page of
+    ``_page_shape``: a row per token, or the whole page as one row of
+    ``width`` numbers a token."""
+    if len(page_shape) == 2:
+        return page_shape
+    return page_shape[0] // width, width
+
+
+def _paged_write(cache, k_new, v_new, posq, token_valid, new_len):
+    """Append ``k_new``/``v_new`` through the page table; returns the new
+    cache with ``len`` set to ``new_len``.
 
     The pool leaves (``POOL_KEYS``, pages of ``_page_shape``) are one
     layer's ``(n_pages, *page)``, or, when the cache also holds a
@@ -400,9 +456,9 @@ def _paged_cache_update(cache, k_new, v_new, posq, token_valid, new_len,
     (donated) pool in place and no layer's pool is sliced out or stacked
     again.
 
-    Write: token (b, i) at absolute position ``posq[b, i]`` lands in pool
-    page ``page_table[b, posq // P]`` at offset ``posq % P``.  The pages
-    a row's new tokens fall in are gathered, the tokens written into them,
+    Token (b, i) at absolute position ``posq[b, i]`` lands in pool page
+    ``page_table[b, posq // P]`` at offset ``posq % P``.  The pages a
+    row's new tokens fall in are gathered, the tokens written into them,
     and the pages written back whole: the chip scatters whole rows of its
     tiles at once but a part of a row one token at a time.  Invalid tokens
     (beyond ``seq_lens``, beyond the table, or aimed at an unassigned
@@ -410,23 +466,17 @@ def _paged_cache_update(cache, k_new, v_new, posq, token_valid, new_len,
     not written back — a slot can never write outside its own assigned
     pages, which is what keeps shared (refcount > 1) pages immutable.  A
     page written back holds the bytes it held wherever no token landed,
-    so the pool is the one a token-by-token write gives.
-
-    Read, after the write: gathering the slot's table rebuilds a
-    contiguous ``(B, n_slot_pages * P, KV, hd)`` view in which view index
-    == absolute position, so validity is ``t <= posq`` (causal) and
-    ``t < new_len`` (written); unassigned table entries gather page 0 but
-    are masked by the length test.
-
-    Returns ``(new_cache, k_read, v_read, valid)`` with f32 read views.
+    so the pool is the one a token-by-token write gives; a padded row's
+    padding stays zero.
     """
     lead = (cache["layer"],) if "layer" in cache else ()
     pt = cache["page_table"]                       # (B, NP)
     b, np_ = pt.shape
-    s, n_kv, hd = k_new.shape[1:]
+    s = k_new.shape[1]
     pool_k = cache["k_pages"]
     n_pages = pool_k.shape[len(lead)]
-    page = math.prod(pool_k.shape[len(lead) + 1:]) // (n_kv * hd)
+    page, _ = _page_rows(pool_k.shape[len(lead) + 1:],
+                         math.prod(k_new.shape[2:]))
     # the pages row b's new tokens can fall in, from that of its first one
     first = posq[:, :1] // page
     n_touch = min(np_, (s + page - 2) // page + 1)
@@ -440,18 +490,16 @@ def _paged_cache_update(cache, k_new, v_new, posq, token_valid, new_len,
     # their end and are dropped
     at = jnp.where(token_valid, posq - first * page, n_touch * page)
     rows = jnp.arange(b, dtype=jnp.int32)[:, None]
-    safe_pt = jnp.where(pt < 0, 0, pt)
 
     def write(key, new):
         pool = cache[key]
-        tail = new.shape[2:]
+        new = new.reshape(b, s, -1)
+        _, row = _page_rows(pool.shape[len(lead) + 1:], new.shape[-1])
+        new = jnp.pad(new, ((0, 0), (0, 0), (0, row - new.shape[-1])))
         pages = pool[lead + (jnp.minimum(tpid, n_pages - 1),)]
-        pages = pages.reshape((b, n_touch * page) + tail).at[rows, at].set(
+        pages = pages.reshape(b, n_touch * page, row).at[rows, at].set(
             cast(new, pool.dtype), mode="drop").reshape(pages.shape)
         return pool.at[lead + (tpid,)].set(pages, mode="drop")
-
-    def read(pool, tail):
-        return pool[lead + (safe_pt,)].reshape((b, np_ * page) + tail)
 
     if "k_scale_pages" in cache:
         k_q, k_s = _kv_quantize(k_new)
@@ -460,21 +508,20 @@ def _paged_cache_update(cache, k_new, v_new, posq, token_valid, new_len,
                "v_pages": write("v_pages", v_q),
                "k_scale_pages": write("k_scale_pages", k_s),
                "v_scale_pages": write("v_scale_pages", v_s)}
-        k_read = _kv_dequant(read(new["k_pages"], (n_kv, hd)),
-                             read(new["k_scale_pages"], (n_kv,)))
-        v_read = _kv_dequant(read(new["v_pages"], (n_kv, hd)),
-                             read(new["v_scale_pages"], (n_kv,)))
     else:
         new = {"k_pages": write("k_pages", k_new),
                "v_pages": write("v_pages", v_new)}
-        k_read = read(new["k_pages"], (n_kv, hd)).astype(jnp.float32)
-        v_read = read(new["v_pages"], (n_kv, hd)).astype(jnp.float32)
-    t_pos = jnp.arange(np_ * page, dtype=jnp.int32)[None, None, :]
-    valid = (t_pos <= posq[:, :, None]) & (t_pos < new_len[:, None, None])
-    if a.sliding_window is not None:
-        valid &= posq[:, :, None] - t_pos < a.sliding_window
-    new_cache = {**cache, **new, "len": new_len}
-    return new_cache, k_read, v_read, valid
+    return {**cache, **new, "len": new_len}
+
+
+def _decode_kernel_serves(cache, a: AttnArgs, s: int) -> bool:
+    """Whether a paged step reads its pages with the paged-decode kernel
+    (``repro.kernels.paged_decode``) in place of the gathered view: one
+    token a slot, bf16 or f32 pages (int8 pages keep the gather), no
+    sliding window, on the TPU.  Chunked prefill (``s > 1``) and the CPU
+    keep the gather."""
+    return (s == 1 and "k_scale_pages" not in cache
+            and a.sliding_window is None and paged_decode_ops.on_tpu())
 
 
 def attn_apply(p, x, a: AttnArgs, *, kv_x=None, positions=None, pos3=None,
@@ -565,12 +612,21 @@ def attn_apply(p, x, a: AttnArgs, *, kv_x=None, positions=None, pos3=None,
         k_new = _apply_rope(k_new, posq, pos3, a)
         new_len = cur + seq_lens
         if _is_paged(cache):
-            new_cache, k_read, v_read, valid = _paged_cache_update(
-                cache, k_new, v_new, posq, token_valid, new_len, a)
-            sc = _gqa_scores(q.astype(jnp.float32), k_read) * scale
-            sc = jnp.where(valid[:, None, None, :, :], sc, NEG)
-            pr = jax.nn.softmax(sc, axis=-1)
-            y = _gqa_out(pr, v_read)
+            if _decode_kernel_serves(cache, a, s):
+                new_cache = _paged_write(cache, k_new, v_new, posq,
+                                         token_valid, new_len)
+                y = paged_decode_ops.paged_decode(
+                    q[:, 0], new_cache["k_pages"], new_cache["v_pages"],
+                    cache["page_table"],
+                    jnp.where(token_valid[:, 0], new_len, 0),
+                    cache.get("layer"), n_kv=a.n_kv)[:, None]
+            else:
+                new_cache, k_read, v_read, valid = _paged_cache_update(
+                    cache, k_new, v_new, posq, token_valid, new_len, a)
+                sc = _gqa_scores(q.astype(jnp.float32), k_read) * scale
+                sc = jnp.where(valid[:, None, None, :, :], sc, NEG)
+                pr = jax.nn.softmax(sc, axis=-1)
+                y = _gqa_out(pr, v_read)
             out = jnp.einsum("bshd,hde->bse", y,
                              p["o"]["w"].astype(jnp.float32))
             return out.astype(compute_dtype), new_cache

@@ -159,6 +159,7 @@ def test_a_served_run_gives_each_admission_and_tick_its_tree(smollm):
         assert kids[0] == "server.tick_control"
         attrs = recs[k][4]
         assert 0 <= attrs["pages_unwritten"] <= attrs["pages_reserved"]
+        assert 0 <= attrs["pages_read"] <= 2 * server.n_slot_pages
         if "model.decode_dispatch" in kids:
             assert kids.count("server.decode_readback") == 1
             decoded += 1
@@ -181,7 +182,25 @@ def test_a_live_slot_counts_its_reserved_pages_unwritten(smollm):
     assert len(req.out) == 2 and len(server.slot_pages[0]) == 5
     counts = server._page_counts()
     assert counts == {"pages_reserved": server.pages_in_use,
-                      "pages_unwritten": 5 - 3}
+                      "pages_unwritten": 5 - 3, "pages_read": 3}
+
+
+def test_pages_read_counts_the_pages_each_fed_slot_reads(smollm):
+    """A tick's ``pages_read`` is what its decode step reads: for each slot
+    it feeds, the pages of the cached tokens and of the one it writes;
+    nothing for an empty slot or a finished request."""
+    cfg, params = smollm
+    server = Server(cfg, params, batch=3, max_len=32, page_size=4)
+    gw = Gateway(server)
+    gw.submit(CompletionRequest(np.arange(1, 10, dtype=np.int32), 12))
+    gw.submit(CompletionRequest(np.arange(1, 5, dtype=np.int32), 12))
+    gw.step()
+    assert [len(r.out) for r in server.slots[:2]] == [2, 2]
+    assert server.slots[2] is None
+    # 9 + 1 and 4 + 1 tokens cached, each writing one more: 11 and 6
+    assert server._page_counts()["pages_read"] == 3 + 2
+    server.slots[1].done = True
+    assert server._page_counts()["pages_read"] == 3
 
 
 def test_serving_executables_lower_under_their_names(smollm):

@@ -252,7 +252,9 @@ def _token_paged_update(cache, k_new, v_new, posq, token_valid, new_len, a):
     b, np_ = pt.shape
     n_kv, hd = k_new.shape[2:]
     n_pages = cache["k_pages"].shape[0]
-    page = math.prod(cache["k_pages"].shape[1:]) // (n_kv * hd)
+    # a row per token (its width padded to whole lanes) or a page per row
+    page = (cache["k_pages"].shape[1] if cache["k_pages"].ndim == 3
+            else cache["k_pages"].shape[1] // (n_kv * hd))
     pid = jnp.take_along_axis(pt, jnp.clip(posq // page, 0, np_ - 1), 1)
     keep = token_valid & (posq < np_ * page) & (pid >= 0)
     pid, off = jnp.where(keep, pid, n_pages), posq % page
@@ -260,11 +262,15 @@ def _token_paged_update(cache, k_new, v_new, posq, token_valid, new_len, a):
     out = {}
 
     def write_read(key, new):
-        pool = cache[key].reshape((n_pages, page) + new.shape[2:])
-        pool = pool.at[pid, off].set(new.astype(pool.dtype), mode="drop")
+        pool = cache[key].reshape(n_pages, page, -1)
+        flat = new.reshape(new.shape[:2] + (-1,))
+        w = flat.shape[-1]
+        flat = jnp.pad(flat, ((0, 0), (0, 0), (0, pool.shape[-1] - w)))
+        pool = pool.at[pid, off].set(flat.astype(pool.dtype), mode="drop")
         out[key] = pool.reshape(cache[key].shape)
         return jnp.take(pool, safe, axis=0).reshape(
-            (b, np_ * page) + new.shape[2:])
+            b, np_ * page, -1)[..., :w].reshape(
+                (b, np_ * page) + new.shape[2:])
 
     if "k_scale_pages" in cache:
         (k_q, k_s), (v_q, v_s) = _kv_quantize(k_new), _kv_quantize(v_new)
@@ -361,8 +367,8 @@ def test_in_place_pool_matches_per_layer_update(pool, case, row):
     layer loop and write whole pages in place, give the logits and every
     cache leaf of the same loop over per-layer slices of the pool written
     token by token; with the layers scanned and with them unrolled.  Two
-    KV heads: ``n_kv * hd`` 32 holds a page as one row, 128 a row per
-    token."""
+    KV heads: ``n_kv * hd`` 32 holds an int8 page as one row and a bf16
+    token as a row padded to 128 lanes, 128 a row per token."""
     from repro.launch.serve import serving_fns
     from repro.models import flags
     cfg = dataclasses.replace(reduce(configs.get("smollm_135m")), n_layers=3,
@@ -371,7 +377,8 @@ def test_in_place_pool_matches_per_layer_update(pool, case, row):
            if pool == "bf16" else dataclasses.replace(cfg, kv_quant=True))
     params = lm.init_params(cfg, jax.random.PRNGKey(3))[0]
     caches, tok, sl = _step_case(cfg, params, case)
-    assert caches["self"]["k_pages"].ndim == (3 if row == "page" else 4)
+    assert caches["self"]["k_pages"].ndim == (
+        3 if row == "page" and pool == "int8" else 4)
     for unroll in (False, True):
         with flags.unrolled() if unroll else contextlib.nullcontext():
             want, want_c = jax.jit(

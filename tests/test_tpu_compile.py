@@ -23,6 +23,8 @@ from repro.core import emit, place
 from repro.core.presets import cluster_6d, tinyml_graph
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.gemm import ops as gemm_ops
+from repro.kernels.paged_decode import ops as paged_decode_ops
+from repro.kernels.paged_decode.ops import paged_decode
 from repro.kernels.maxpool import ops as maxpool_ops
 from repro.kernels.rmsnorm.ops import rmsnorm
 from repro.kernels.ssd.ops import ssd_chunked
@@ -85,6 +87,24 @@ def test_flash_attention_gqa_compiles(one_chip):
                 q, kv, kv)
 
 
+@pytest.mark.parametrize("h,n_kv,hd,layers,n_pages,n_slot_pages", [
+    (9, 3, 64, 30, 2048, 112),      # smollm-135m, the chat cell's pool
+    (32, 32, 80, 0, 768, 96),       # stablelm-3b, one layer's pool
+], ids=["smollm", "stablelm"])
+def test_paged_decode_compiles(one_chip, h, n_kv, hd, layers, n_pages,
+                               n_slot_pages):
+    width = -(-n_kv * hd // 128) * 128
+    pool = _spec(one_chip, ((layers,) if layers else ()) + (
+        n_pages, 16, width), "bfloat16")
+    b = 16 if layers else 8
+    layer = _spec(one_chip, (), "int32") if layers else None
+    _kernel_hlo(lambda q, k, v, t, n, i: paged_decode(
+        q, k, v, t, n, i, n_kv=n_kv, interpret=False),
+        _spec(one_chip, (b, h, hd), "bfloat16"), pool, pool,
+        _spec(one_chip, (b, n_slot_pages), "int32"),
+        _spec(one_chip, (b,), "int32"), layer)
+
+
 def test_rmsnorm_compiles(one_chip):
     _kernel_hlo(lambda x, w: rmsnorm(x, w, interpret=False),
                 _spec(one_chip, (512, 576), "bfloat16"),
@@ -131,14 +151,20 @@ def test_tinyml_program_compiles(one_chip, monkeypatch):
 
 
 @pytest.mark.parametrize("step", ["prefill", "decode"])
-def test_smollm_serving_step_compiles(one_chip, step):
+def test_smollm_serving_step_compiles(one_chip, step, monkeypatch):
     """smollm-135m's serving steps at its published widths, on the paged
     cache and at the shapes the chip smoke serves (prefill at its widest
     prompt bucket), fit one chip.  The chip's default layout of the pool's
     lane-dense rows is the one its page writes and reads use, so the
     donated pool is updated where it lies, with no copy of it; and the
     token writes are native scatters, not a loop over the tokens (a second
-    ``while`` beside the layer loop's)."""
+    ``while`` beside the layer loop's).  The decode step reads the pages
+    with the compiled paged-decode kernel and holds no temporary buffer,
+    as before the kernel."""
+    if step == "decode":
+        # the model asks the (CPU) default backend; steer it to the chip's
+        monkeypatch.setattr(paged_decode_ops, "on_tpu", lambda: True)
+        monkeypatch.setattr(paged_decode_ops, "use_interpret", lambda: False)
     cfg = configs.get("smollm_135m")
     t = chip_smoke.Traffic()
     width = _bucket(t.prompt_max) if step == "prefill" else 1
@@ -160,3 +186,6 @@ def test_smollm_serving_step_compiles(one_chip, step):
     hlo = compiled.as_text()
     assert not pool_copies(hlo, caches["self"]["k_pages"].shape)
     assert hlo.count(" while(") == 1
+    if step == "decode":
+        assert "tpu_custom_call" in hlo
+        assert mem.temp_size_in_bytes == 0
